@@ -38,7 +38,8 @@ from repro.core import area, qat
 from repro.core import nonideal as nonideal_lib
 from repro.core.nonideal import NonIdealSpec
 from repro.core.spec import AdcSpec, Range
-from repro.core.search import (SearchConfig, decode_genome_cosearch,
+from repro.core.search import (SearchConfig, accuracy,
+                               decode_genome_cosearch,
                                decode_genome_faulttol, train_pareto_front)
 from repro.faulttol import calibrate as faulttol_cal
 from repro.faulttol import redundancy as ft_redundancy
@@ -123,7 +124,7 @@ class DeployedClassifier:
         return np.argmax(self.logits(x, interpret=interpret), axis=-1)
 
     def accuracy_on(self, x, y, interpret: Optional[bool] = None) -> float:
-        return float(_jnp_mean_acc(
+        return float(mean_acc(
             self.predict(x, interpret=interpret)[None] == np.asarray(y))[0])
 
 
@@ -437,26 +438,24 @@ def served_accuracies(designs: Sequence[DeployedClassifier], x, y, *,
     """(D,) test accuracies of the whole served front — the round-trip
     parity check against each design's exported ``accuracy``."""
     logits = serve_bank(designs, x, mesh=mesh, interpret=interpret)
-    return _jnp_mean_acc(np.argmax(logits, -1) == np.asarray(y)[None, :])
+    return mean_acc(np.argmax(logits, -1) == np.asarray(y)[None, :])
 
 
-def _jnp_mean_acc(correct: np.ndarray) -> np.ndarray:
-    """(D, M) correctness bools -> (D,) f32 accuracies via ``jnp.mean`` —
-    the *same op* the search-time fitness uses (models.{mlp,svm}.accuracy).
-    XLA lowers the mean to ``sum * reciprocal(M)`` in f32; a host-side
-    ``np.mean`` (f64, true division) differs in the last ulp and would
-    break the bit-for-bit round-trip contract."""
-    import jax.numpy as jnp
-    return np.asarray(jnp.mean(jnp.asarray(correct), axis=-1))
+def mean_acc(correct: np.ndarray) -> np.ndarray:
+    """(D, M) correctness bools -> (D,) accuracies through
+    ``search.accuracy``, the division the search-time fitness uses, so
+    served and exported accuracies compare bit for bit."""
+    correct = np.asarray(correct)
+    return accuracy(correct.sum(axis=-1), correct.shape[-1])
 
 
 # -------------------------------------------------- robustness (DESIGN §10)
 def _stacked_model_params(designs: Sequence[DeployedClassifier]):
     """The front's baked weights re-assembled as the model family's params
     pytree with a leading design axis — the exact structure
-    models.{mlp,svm}.accuracy consumes, so the Monte-Carlo accuracy path
+    models.{mlp,svm}.n_correct consumes, so the Monte-Carlo accuracy path
     below is op-for-op the in-search robustness objective
-    (search._mc_accuracy_fn) evaluated on the exported numbers. The
+    (search._mc_correct_fn) evaluated on the exported numbers. The
     stacking itself is ``bank_arrays``' (one site owns the weight-leaf
     layout); this only regroups the flat leaves into params."""
     import jax.numpy as jnp
@@ -516,13 +515,13 @@ def _mc_instance_accuracies(designs: Sequence[DeployedClassifier],
         mc = nonideal_lib.mc_operands(spec, nonideal, masks, draws=draws)
         xq_mc = dispatch.dispatch("mc_eval_population", xj, *mc, spec=spec,
                                   interpret=interpret)   # (D, S, M, C)
-    acc = svm_lib.accuracy if d0.kind == "svm" else mlp_lib.accuracy
+    count = svm_lib.n_correct if d0.kind == "svm" else mlp_lib.n_correct
     # dp=None: the baked weights are already po2/fixed-quantized at
     # export; re-quantization would be a no-op by construction and the
     # in-graph path was only ever there for traced search-time dp
-    per_design = lambda p, xq_s: jax.vmap(lambda xq: acc(p, xq, yj))(xq_s)
-    return np.asarray(jax.vmap(per_design)(_stacked_model_params(designs),
-                                           xq_mc))
+    per_design = lambda p, xq_s: jax.vmap(lambda xq: count(p, xq, yj))(xq_s)
+    correct = jax.vmap(per_design)(_stacked_model_params(designs), xq_mc)
+    return accuracy(correct, len(yj))
 
 
 def evaluate_robustness(designs: Sequence[DeployedClassifier],

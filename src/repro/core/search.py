@@ -47,7 +47,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec
 
-from repro import compat
 from repro.core import adc, area, nsga2
 from repro.core import nonideal as nonideal_lib
 from repro.core.nonideal import NonIdealSpec
@@ -336,10 +335,11 @@ def _init_model(sizes, cfg: SearchConfig):
 def _train_from_quantized(xq_tr, xq_te, y_tr, y_te, dp, params, opt,
                           sizes, cfg: SearchConfig,
                           return_params: bool = False):
-    """QAT one individual from its already-quantized inputs: returns test
-    accuracy (scalar), or ``(accuracy, trained params)`` with
-    ``return_params`` — the export path keeps the parameters the fitness
-    was measured on instead of throwing them away. vmap target — all
+    """QAT one individual from its already-quantized inputs: returns the
+    int32 count of correct test predictions (``accuracy`` divides it on
+    the host), or ``(count, trained params)`` with ``return_params`` —
+    the export path keeps the parameters the fitness was measured on
+    instead of throwing them away. vmap target — all
     operands carry the population axis at the call site; ``dp`` may be
     traced per individual."""
     from repro.models import svm as svm_lib
@@ -351,8 +351,8 @@ def _train_from_quantized(xq_tr, xq_te, y_tr, y_te, dp, params, opt,
     if cfg.model == "svm":
         loss_of = lambda p: svm_lib.svm_loss(p, xq_tr, y_tr, dp,
                                              weight_bits=cfg.weight_bits)
-        acc_of = lambda p: svm_lib.accuracy(p, xq_te, y_te, dp,
-                                            cfg.weight_bits)
+        correct_of = lambda p: svm_lib.n_correct(p, xq_te, y_te, dp,
+                                                 cfg.weight_bits)
     else:
         def loss_of(p):
             logits = mlp_lib.apply_mlp(p, xq_tr, dp, cfg.weight_bits)
@@ -360,8 +360,8 @@ def _train_from_quantized(xq_tr, xq_te, y_tr, y_te, dp, params, opt,
             onehot = jax.nn.one_hot(y_tr, sizes[-1])
             return -(onehot * logp).sum(-1).mean()
 
-        acc_of = lambda p: mlp_lib.accuracy(p, xq_te, y_te, dp,
-                                            cfg.weight_bits)
+        correct_of = lambda p: mlp_lib.n_correct(p, xq_te, y_te, dp,
+                                                 cfg.weight_bits)
 
     def step(carry, _):
         p, o = carry
@@ -371,8 +371,8 @@ def _train_from_quantized(xq_tr, xq_te, y_tr, y_te, dp, params, opt,
 
     (params, _), _ = jax.lax.scan(step, (params, opt), length=cfg.train_steps)
     if return_params:
-        return acc_of(params), params
-    return acc_of(params)
+        return correct_of(params), params
+    return correct_of(params)
 
 
 def _train_eval_one(genome, data, sizes, cfg: SearchConfig,
@@ -380,7 +380,7 @@ def _train_eval_one(genome, data, sizes, cfg: SearchConfig,
     """QAT one individual end-to-end (decode -> quantize -> train). The
     paper-faithful sequential path; also the per-individual parity oracle
     for the batched engine. With a robustness-enabled config and
-    ``draws`` returns ``(accuracy, (S,) per-instance MC accuracies)`` —
+    ``draws`` returns ``(correct, (S,) per-instance MC correct counts)`` —
     the single-design MC entry standing in for the population launch."""
     channels = sizes[0]
     if cfg.frontend is not None:
@@ -416,7 +416,7 @@ def _train_eval_one(genome, data, sizes, cfg: SearchConfig,
                                 cfg, return_params=robust)
     if not robust:
         return out
-    acc, trained = out
+    correct, trained = out
     if cfg.faulttol is not None:
         from repro.kernels import dispatch
         ft_ops = faulttol_cal.mc_operands_ft(cfg.adc_spec, cfg.nonideal,
@@ -427,34 +427,43 @@ def _train_eval_one(genome, data, sizes, cfg: SearchConfig,
         xq_mc = nonideal_lib.mc_quantize(data["x_test"], mask,
                                          cfg.adc_spec, cfg.nonideal,
                                          draws=draws)
-    return acc, _mc_accuracy_fn(data, cfg)(trained, dp, xq_mc)   # (S,)
+    return correct, _mc_correct_fn(data, cfg)(trained, dp, xq_mc)  # (S,)
 
 
-def _mc_accuracy_fn(data: Dict, cfg: SearchConfig):
-    """Per-individual MC accuracy: (trained params, dp, xq (S, M, C)) ->
-    (S,) test accuracies — the same model-accuracy op as the ideal
-    fitness, vmapped over the MC instance axis."""
+def _mc_correct_fn(data: Dict, cfg: SearchConfig):
+    """Per-individual MC scoring: (trained params, dp, xq (S, M, C)) ->
+    (S,) correct test counts — the same op as the ideal fitness, vmapped
+    over the MC instance axis."""
     from repro.models import svm as svm_lib
-    acc = svm_lib.accuracy if cfg.model == "svm" else mlp_lib.accuracy
+    count = svm_lib.n_correct if cfg.model == "svm" else mlp_lib.n_correct
 
     def fn(params, dp, xq_s):
-        one = lambda xq: acc(params, xq, data["y_test"], dp,
-                             cfg.weight_bits)
+        one = lambda xq: count(params, xq, data["y_test"], dp,
+                               cfg.weight_bits)
         return jax.vmap(one)(xq_s)
 
     return fn
+
+
+def accuracy(correct, n: int) -> np.ndarray:
+    """Correct counts -> f32 accuracies: the count over ``n`` divided on
+    the host and rounded once to f32. Every engine, the export and the
+    serving parity checks divide here, so a design's accuracy is the
+    same number on every path and backend. f32 keeps the host-side f64
+    MC reductions exact (nonideal.mc_mean_accuracy)."""
+    return (np.asarray(correct, np.float64) / n).astype(np.float32)
 
 
 def _train_and_score(genomes: jnp.ndarray, params0, opt0, data: Dict,
                      sizes: Tuple[int, ...], cfg: SearchConfig,
                      return_params: bool = False,
                      draws: Optional[nonideal_lib.Draws] = None) -> Dict:
-    """(P, G) genomes -> ``{'acc': (P,) test accuracies}`` as ONE compiled
-    program; ``return_params=True`` adds the trained parameter stacks
-    under ``'params'`` (each leaf (P, ...) — the raw material of a
+    """(P, G) genomes -> ``{'correct': (P,) correct test counts}`` as ONE
+    compiled program; ``return_params=True`` adds the trained parameter
+    stacks under ``'params'`` (each leaf (P, ...) — the raw material of a
     deployment export, core/deploy.py); a robustness-enabled config plus
-    ``draws`` adds ``'mc_accs'``, the raw (P, S) per-instance MC
-    accuracies: the MC population kernel pushes the shared test batch
+    ``draws`` adds ``'mc_correct'``, the raw (P, S) per-instance MC
+    correct counts: the MC population kernel pushes the shared test batch
     through ``cfg.mc_samples`` perturbed instances of every individual's
     ADC (one (P, S, M/bm) launch) and the trained models re-score each
     perturbed view (DESIGN.md §10); callers reduce the third fitness
@@ -501,8 +510,8 @@ def _train_and_score(genomes: jnp.ndarray, params0, opt0, data: Dict,
         xtr, xte, data["y_train"], data["y_test"], dp, p, o, sizes, cfg,
         want_params)
     out = jax.vmap(fn)(xq_tr, xq_te, dps, params0, opt0)
-    accs, params = out if want_params else (out, None)
-    result = {"acc": accs}
+    correct, params = out if want_params else (out, None)
+    result = {"correct": correct}
     if robust:
         from repro.kernels import dispatch
         if cfg.faulttol is not None:
@@ -520,11 +529,11 @@ def _train_and_score(genomes: jnp.ndarray, params0, opt0, data: Dict,
             xq_mc = dispatch.dispatch("mc_eval_population",
                                       data["x_test"], *mc,
                                       spec=spec)           # (P, S, M, C)
-        # per-instance accuracies leave the compiled program raw; the
+        # per-instance counts leave the compiled program raw; the
         # objective reduction happens host-side in f64
         # (nonideal.robust_objective) so the search fitness and
         # deploy.evaluate_robustness agree bit-for-bit
-        result["mc_accs"] = jax.vmap(_mc_accuracy_fn(data, cfg))(
+        result["mc_correct"] = jax.vmap(_mc_correct_fn(data, cfg))(
             params, dps, xq_mc)
     if return_params:
         result["params"] = params
@@ -569,16 +578,6 @@ def search_draws(cfg: SearchConfig, channels: int):
                              cfg.nonideal)
 
 
-def evaluate_population_acc(genomes: jnp.ndarray, data: Dict,
-                            sizes: Tuple[int, ...], cfg: SearchConfig
-                            ) -> jnp.ndarray:
-    """(P, G) genomes -> (P,) test accuracies. One vmapped QAT program —
-    convenience wrapper that builds the donated initial buffers itself."""
-    params0, opt0 = _stacked_init(genomes.shape[0], sizes, cfg)
-    return _train_and_score_jit()(jnp.asarray(genomes, jnp.uint8), params0,
-                                  opt0, data, tuple(sizes), cfg)["acc"]
-
-
 def train_pareto_front(genomes: np.ndarray, data: Dict,
                        sizes: Tuple[int, ...], cfg: SearchConfig):
     """Re-train the given (typically Pareto-front) genomes and keep what
@@ -598,7 +597,8 @@ def train_pareto_front(genomes: np.ndarray, data: Dict,
     out = _train_and_score_jit()(
         jnp.asarray(genomes), params0, opt0, dev_data, tuple(sizes), cfg,
         return_params=True)
-    accs, params = out["acc"], out["params"]
+    accs = accuracy(out["correct"], len(data["y_test"]))
+    params = out["params"]
     if cfg.frontend is not None:
         # alloc-applied masks: the exported design must bake the SAME
         # pruned levels the fitness was measured on
@@ -719,13 +719,22 @@ def evaluate_population(genomes: np.ndarray, data: Dict, sizes,
             tuple(sizes), cfg, draws=draws)
         return {k: np.asarray(v) for k, v in out.items()}
 
-    out = _eval_dedup(genomes, cfg, core)
-    cols = [1.0 - np.asarray(out["acc"]),
-            population_areas(genomes, sizes[0], cfg)]
-    if "mc_accs" in out:
+    return _fitness(_eval_dedup(genomes, cfg, core), genomes, data, sizes,
+                    cfg)
+
+
+def _fitness(out: Dict, genomes: np.ndarray, data: Dict, sizes,
+             cfg: SearchConfig) -> np.ndarray:
+    """Correct counts (+ MC counts) -> the minimized fitness columns
+    [1 - accuracy, normalized area(, robustness)], reduced on the host
+    in f64 — shared by every engine."""
+    n = len(data["y_test"])
+    accs = accuracy(out["correct"], n)
+    cols = [1.0 - accs, population_areas(genomes, sizes[0], cfg)]
+    if "mc_correct" in out:
         cols.append(nonideal_lib.robust_objective(
-            np.asarray(out["acc"]), np.asarray(out["mc_accs"]),
-            cfg.robust_objective, margin=cfg.yield_margin))
+            accs, accuracy(out["mc_correct"], n), cfg.robust_objective,
+            margin=cfg.yield_margin))
     return np.stack(cols, axis=1)
 
 
@@ -735,8 +744,8 @@ def default_search_mesh() -> jax.sharding.Mesh:
     individuals are embarrassingly parallel, so every chip takes
     population slices. (A caller with a real 2D mesh passes it in and
     population_axes folds both axes into the population split.)"""
-    return compat.make_mesh((len(jax.devices()), 1), ("data", "model"),
-                            axis_types=(compat.AxisType.Auto,) * 2)
+    return jax.make_mesh((len(jax.devices()), 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 @functools.lru_cache(maxsize=8)
@@ -762,7 +771,7 @@ def _sharded_train_and_score(mesh, axes, sizes, cfg: SearchConfig):
     # shards); every output leaf (acc, robust) carries the population
     # axis, so the single pspec prefix covers the dict.
     donate = (1, 2) if jax.default_backend() != "cpu" else ()
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec, pspec, pspec, PartitionSpec(), PartitionSpec()),
         out_specs=pspec, check_vma=False), donate_argnums=donate)
@@ -798,18 +807,12 @@ def evaluate_population_sharded(genomes: np.ndarray, data: Dict, sizes,
                      draws)
         return {k: np.asarray(v) for k, v in out.items()}
 
-    out = _eval_dedup(genomes, cfg, core)
-    cols = [1.0 - np.asarray(out["acc"]),
-            population_areas(genomes, sizes[0], cfg)]
-    if "mc_accs" in out:
-        cols.append(nonideal_lib.robust_objective(
-            np.asarray(out["acc"]), np.asarray(out["mc_accs"]),
-            cfg.robust_objective, margin=cfg.yield_margin))
-    return np.stack(cols, axis=1)
+    return _fitness(_eval_dedup(genomes, cfg, core), genomes, data, sizes,
+                    cfg)
 
 
 @functools.partial(jax.jit, static_argnames=("sizes", "cfg"))
-def _eval_one_acc(genome, data, sizes, cfg: SearchConfig, draws=None):
+def _eval_one(genome, data, sizes, cfg: SearchConfig, draws=None):
     return _train_eval_one(genome, data, sizes, cfg, draws=draws)
 
 
@@ -823,19 +826,15 @@ def evaluate_population_reference(genomes: np.ndarray, data: Dict, sizes,
     if draws is None:
         draws = search_draws(cfg, sizes[0])
     dev_data = {k: jnp.asarray(v) for k, v in data.items()}
-    rows = [_eval_one_acc(jnp.asarray(g, jnp.uint8), dev_data,
-                          tuple(sizes), cfg, draws=draws)
+    rows = [_eval_one(jnp.asarray(g, jnp.uint8), dev_data,
+                      tuple(sizes), cfg, draws=draws)
             for g in genomes]
-    areas = population_areas(genomes, sizes[0], cfg)
     if cfg.wants_robustness:
-        accs = np.array([float(a) for a, _ in rows])
-        mc_accs = np.stack([np.asarray(m) for _, m in rows])
-        robust = nonideal_lib.robust_objective(accs, mc_accs,
-                                               cfg.robust_objective,
-                                               margin=cfg.yield_margin)
-        return np.stack([1.0 - accs, areas, robust], axis=1)
-    accs = np.array([float(a) for a in rows])
-    return np.stack([1.0 - accs, areas], axis=1)
+        out = {"correct": np.array([int(c) for c, _ in rows]),
+               "mc_correct": np.stack([np.asarray(m) for _, m in rows])}
+    else:
+        out = {"correct": np.array([int(c) for c in rows])}
+    return _fitness(out, genomes, data, sizes, cfg)
 
 
 def make_eval_fn(data: Dict, sizes, cfg: SearchConfig,

@@ -30,13 +30,18 @@ def bank_pool_mesh(devices: Sequence):
     the serving engine's re-shard target. The design-bank population
     rules (distributed/sharding.design_bank_axes) partition the bank's D
     axis over 'data' when it divides, else fall back to replicated; the
-    same divisibility contract a fresh mesh gets, applied to survivors."""
+    same divisibility contract a fresh mesh gets, applied to survivors.
+
+    Built with ``Mesh`` over the devices in the order given, not with
+    ``jax.make_mesh``: on TPU the latter maps the list onto the physical
+    torus and refuses a set of survivors that no longer fills it (3 of a
+    2x2)."""
+    import numpy as np
+    from jax.sharding import Mesh
     devices = list(devices)
     if not devices:
         raise ValueError("bank_pool_mesh needs at least one device")
-    from repro import compat
-    return compat.make_mesh((len(devices), 1), ("data", "model"),
-                            devices=devices)
+    return Mesh(np.array(devices).reshape(len(devices), 1), ("data", "model"))
 
 
 def plan_mesh(n_devices: int, *, model: int = 16, chips_per_pod: int = 256):

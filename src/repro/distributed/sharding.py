@@ -17,7 +17,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map  # noqa: F401  (version-shimmed re-export)
 
 # candidates tried in order; a candidate applies iff all its axes exist in
 # the mesh, none is already used in this tensor, and the dim divides evenly.

@@ -50,6 +50,8 @@ import dataclasses
 import logging
 from typing import Callable, Dict, Optional, Tuple
 
+import jax
+
 from repro.kernels import envelope, ref
 from repro.kernels.adc_quantize import (adc_quantize_pallas,
                                         adc_quantize_pallas_population)
@@ -212,13 +214,20 @@ def resolve(name: str, spec, channels: int,
                       "auto off-TPU: interpret kernel", bm, bm_src)
 
 
-def _log(res: Resolution) -> None:
-    key = (res.entry, res.path, res.interpret, res.sharded,
-           res.block_m, res.block_m_source)
+def _log(res: Resolution, workload: Optional[Workload] = None) -> None:
+    """Log a routing decision; a heuristic tile is logged as the value
+    the kernel's VMEM heuristic picks for ``workload`` (when known)."""
+    bm = res.block_m
+    if bm is None and res.block_m_source == "heuristic" \
+            and workload is not None:
+        from repro.perf import cost_model
+        bm = cost_model.heuristic_block_m(workload)
+    key = (res.entry, res.path, res.interpret, res.sharded, bm,
+           res.block_m_source)
     level = logging.DEBUG if key in _LOGGED else logging.INFO
     _LOGGED.add(key)
     tile = ("" if res.block_m_source is None
-            else f"[block_m={res.block_m or 'auto'}:{res.block_m_source}]")
+            else f"[block_m={bm or 'auto'}:{res.block_m_source}]")
     log.log(level, "dispatch %s -> %s%s%s (%s)", res.entry, res.path,
             "" if res.interpret is None else f"[interpret={res.interpret}]",
             tile, res.reason)
@@ -240,10 +249,10 @@ def _workload_of(name: str, x, tables, weights, spec
 def _run(name: str, x, tables, *weights, spec,
          interpret: Optional[bool], log_resolution: bool):
     entry = get(name)
-    res = resolve(name, spec, x.shape[-1], interpret,
-                  workload=_workload_of(name, x, tables, weights, spec))
+    workload = _workload_of(name, x, tables, weights, spec)
+    res = resolve(name, spec, x.shape[-1], interpret, workload=workload)
     if log_resolution:
-        _log(res)
+        _log(res, workload)
     if res.path == "oracle":
         return entry.oracle(x, tables, *weights, spec=spec)
     return entry.kernel(x, tables, *weights, spec=spec,
@@ -270,8 +279,6 @@ def dispatch_sharded(name: str, x, tables, *weights, spec, mesh, axes=None,
     either way."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     entry = get(name)
     if entry.sharded_axes is None:
         raise ValueError(f"kernel entry {name!r} has no sharded variant")
@@ -290,9 +297,10 @@ def dispatch_sharded(name: str, x, tables, *weights, spec, mesh, axes=None,
         return _run(name, xs, ts, *ws, spec=spec, interpret=interpret,
                     log_resolution=False)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(),) + (pspec,) * (1 + len(weights)),
-                     out_specs=pspec, check_vma=False)(x, tables, *weights)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(),) + (pspec,) * (1 + len(weights)),
+                       out_specs=pspec, check_vma=False)
+    return fn(x, tables, *weights)
 
 
 # --------------------------------------------------------------- registry

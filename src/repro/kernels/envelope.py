@@ -23,9 +23,19 @@ One place answers the three questions the dispatch registry
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 MAX_UNROLL_BITS = 6
 MAX_CHANNELS = 4096
+
+# The f32 matmul precision of the fused classifier kernels AND of every
+# forward they are compared with (the QAT forward in models/, the jnp
+# oracles in kernels/ref.py). On TPU, XLA's default f32 dot is one bf16
+# pass while Mosaic's need not be; with po2-quantized weights and
+# few-bit ADC levels every product and partial sum is exact at f32
+# precision, so stating HIGHEST on both sides keeps search fitness,
+# exported and served accuracy equal bit for bit on the chip.
+MATMUL_PRECISION = jax.lax.Precision.HIGHEST
 
 # ~2 MB of f32 VMEM for the streamed x + out tiles and the resident
 # operands: half a conservative 4 MB working budget, leaving room for the
@@ -36,6 +46,14 @@ VMEM_BUDGET_F32 = (1 << 21) // 4
 def interpret_default() -> bool:
     """True when Pallas should run in interpret mode (any non-TPU backend)."""
     return jax.default_backend() != "tpu"
+
+
+def unit_rows(a):
+    """(..., X) -> f32 (..., 1, X). Mosaic tiles the last two block dims,
+    so a per-design or per-instance row (bank biases, MC range rows)
+    must be a whole (1, X) slab rather than a (1, X) slice of a
+    (D|S, X) array."""
+    return a.astype(jnp.float32)[..., None, :]
 
 
 def outside_envelope(bits: int, channels: int) -> bool:

@@ -76,7 +76,7 @@ def _mc_tile(x, lb, ub, values, lo, scale):
 def _mc_kernel(x_ref, lb_ref, ub_ref, val_ref, lo_ref, scale_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)                 # (bm, C)
     out = _mc_tile(x, lb_ref[0], ub_ref[0], val_ref[...],
-                   lo_ref[...], scale_ref[...])
+                   lo_ref[0], scale_ref[0])
     o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -84,7 +84,7 @@ def _mc_pop_kernel(x_ref, lb_ref, ub_ref, val_ref, lo_ref, scale_ref,
                    o_ref):
     x = x_ref[...].astype(jnp.float32)                 # (bm, C)
     out = _mc_tile(x, lb_ref[0, 0], ub_ref[0, 0], val_ref[...],
-                   lo_ref[...], scale_ref[...])
+                   lo_ref[0], scale_ref[0])
     o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
@@ -97,7 +97,6 @@ def mc_adc_eval_pallas(x: jnp.ndarray, lb: jnp.ndarray, ub: jnp.ndarray,
     """x (M, C); lb/ub (S, C, 2^N); values (C, 2^N); lo/scale (S, C).
     Returns (S, M, C) — S perturbed instances in one launch."""
     if interpret is None:
-        from repro.kernels import envelope
         interpret = envelope.interpret_default()
     m, c = x.shape
     s, _, n = lb.shape
@@ -115,13 +114,14 @@ def mc_adc_eval_pallas(x: jnp.ndarray, lb: jnp.ndarray, ub: jnp.ndarray,
             pl.BlockSpec((1, c, n), lambda si, i: (si, 0, 0)),
             pl.BlockSpec((1, c, n), lambda si, i: (si, 0, 0)),
             pl.BlockSpec((c, n), lambda si, i: (0, 0)),
-            pl.BlockSpec((1, c), lambda si, i: (si, 0)),
-            pl.BlockSpec((1, c), lambda si, i: (si, 0)),
+            pl.BlockSpec((1, 1, c), lambda si, i: (si, 0, 0)),
+            pl.BlockSpec((1, 1, c), lambda si, i: (si, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bm, c), lambda si, i: (si, i, 0)),
         out_shape=jax.ShapeDtypeStruct((s, x.shape[0], c), x.dtype),
         interpret=interpret,
-    )(x, f32(lb), f32(ub), f32(values), f32(lo), f32(scale))
+    )(x, f32(lb), f32(ub), f32(values), envelope.unit_rows(lo),
+      envelope.unit_rows(scale))
     return out[:, :m]
 
 
@@ -138,7 +138,6 @@ def mc_adc_eval_pallas_population(x: jnp.ndarray, lb: jnp.ndarray,
     (P, S, M/bm) launch, instance operands VMEM-resident across the
     inner M axis."""
     if interpret is None:
-        from repro.kernels import envelope
         interpret = envelope.interpret_default()
     m, c = x.shape
     p, s, _, n = lb.shape
@@ -156,14 +155,15 @@ def mc_adc_eval_pallas_population(x: jnp.ndarray, lb: jnp.ndarray,
             pl.BlockSpec((1, 1, c, n), lambda pi, si, i: (pi, si, 0, 0)),
             pl.BlockSpec((1, 1, c, n), lambda pi, si, i: (pi, si, 0, 0)),
             pl.BlockSpec((c, n), lambda pi, si, i: (0, 0)),
-            pl.BlockSpec((1, c), lambda pi, si, i: (si, 0)),
-            pl.BlockSpec((1, c), lambda pi, si, i: (si, 0)),
+            pl.BlockSpec((1, 1, c), lambda pi, si, i: (si, 0, 0)),
+            pl.BlockSpec((1, 1, c), lambda pi, si, i: (si, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bm, c),
                                lambda pi, si, i: (pi, si, i, 0)),
         out_shape=jax.ShapeDtypeStruct((p, s, x.shape[0], c), x.dtype),
         interpret=interpret,
-    )(x, f32(lb), f32(ub), f32(values), f32(lo), f32(scale))
+    )(x, f32(lb), f32(ub), f32(values), envelope.unit_rows(lo),
+      envelope.unit_rows(scale))
     return out[:, :, :m]
 
 
@@ -178,7 +178,7 @@ def _mc_cal_kernel(x_ref, lb_ref, ub_ref, val_ref, lo_ref, scale_ref,
                    o_ref):
     x = x_ref[...].astype(jnp.float32)                 # (bm, C)
     out = _mc_tile(x, lb_ref[0], ub_ref[0], val_ref[0],
-                   lo_ref[...], scale_ref[...])
+                   lo_ref[0], scale_ref[0])
     o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -186,7 +186,7 @@ def _mc_cal_pop_kernel(x_ref, lb_ref, ub_ref, val_ref, lo_ref, scale_ref,
                        o_ref):
     x = x_ref[...].astype(jnp.float32)                 # (bm, C)
     out = _mc_tile(x, lb_ref[0, 0], ub_ref[0, 0], val_ref[0, 0],
-                   lo_ref[...], scale_ref[...])
+                   lo_ref[0], scale_ref[0])
     o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
@@ -199,7 +199,6 @@ def mc_adc_eval_cal_pallas(x: jnp.ndarray, lb: jnp.ndarray,
     """x (M, C); lb/ub AND values (S, C, 2^N) per instance (calibrated
     reconstruction ladders); lo/scale (S, C). Returns (S, M, C)."""
     if interpret is None:
-        from repro.kernels import envelope
         interpret = envelope.interpret_default()
     m, c = x.shape
     s, _, n = lb.shape
@@ -217,13 +216,14 @@ def mc_adc_eval_cal_pallas(x: jnp.ndarray, lb: jnp.ndarray,
             pl.BlockSpec((1, c, n), lambda si, i: (si, 0, 0)),
             pl.BlockSpec((1, c, n), lambda si, i: (si, 0, 0)),
             pl.BlockSpec((1, c, n), lambda si, i: (si, 0, 0)),
-            pl.BlockSpec((1, c), lambda si, i: (si, 0)),
-            pl.BlockSpec((1, c), lambda si, i: (si, 0)),
+            pl.BlockSpec((1, 1, c), lambda si, i: (si, 0, 0)),
+            pl.BlockSpec((1, 1, c), lambda si, i: (si, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bm, c), lambda si, i: (si, i, 0)),
         out_shape=jax.ShapeDtypeStruct((s, x.shape[0], c), x.dtype),
         interpret=interpret,
-    )(x, f32(lb), f32(ub), f32(values), f32(lo), f32(scale))
+    )(x, f32(lb), f32(ub), f32(values), envelope.unit_rows(lo),
+      envelope.unit_rows(scale))
     return out[:, :m]
 
 
@@ -239,7 +239,6 @@ def mc_adc_eval_cal_pallas_population(x: jnp.ndarray, lb: jnp.ndarray,
     into their value rows); lo/scale (S, C) shared. Returns (P, S, M, C)
     — the fault-tolerant co-search's compiled inner loop."""
     if interpret is None:
-        from repro.kernels import envelope
         interpret = envelope.interpret_default()
     m, c = x.shape
     p, s, _, n = lb.shape
@@ -257,12 +256,13 @@ def mc_adc_eval_cal_pallas_population(x: jnp.ndarray, lb: jnp.ndarray,
             pl.BlockSpec((1, 1, c, n), lambda pi, si, i: (pi, si, 0, 0)),
             pl.BlockSpec((1, 1, c, n), lambda pi, si, i: (pi, si, 0, 0)),
             pl.BlockSpec((1, 1, c, n), lambda pi, si, i: (pi, si, 0, 0)),
-            pl.BlockSpec((1, c), lambda pi, si, i: (si, 0)),
-            pl.BlockSpec((1, c), lambda pi, si, i: (si, 0)),
+            pl.BlockSpec((1, 1, c), lambda pi, si, i: (si, 0, 0)),
+            pl.BlockSpec((1, 1, c), lambda pi, si, i: (si, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bm, c),
                                lambda pi, si, i: (pi, si, i, 0)),
         out_shape=jax.ShapeDtypeStruct((p, s, x.shape[0], c), x.dtype),
         interpret=interpret,
-    )(x, f32(lb), f32(ub), f32(values), f32(lo), f32(scale))
+    )(x, f32(lb), f32(ub), f32(values), envelope.unit_rows(lo),
+      envelope.unit_rows(scale))
     return out[:, :, :m]

@@ -20,6 +20,7 @@ there.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.spec import AdcSpec, as_spec
@@ -83,7 +84,6 @@ def adc_quantize_population_sharded(x: jnp.ndarray, masks: jnp.ndarray, *,
     single-device path runs unsharded (same results)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.distributed import sharding as sharding_lib
 
     spec = as_spec(spec)
@@ -102,8 +102,8 @@ def adc_quantize_population_sharded(x: jnp.ndarray, masks: jnp.ndarray, *,
         return adc_quantize_population(xs, ms, spec=spec,
                                        interpret=interpret)
 
-    return shard_map(body, mesh=mesh, in_specs=(P(), pspec),
-                     out_specs=pspec, check_vma=False)(x, masks)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(), pspec),
+                         out_specs=pspec, check_vma=False)(x, masks)
 
 
 # ------------------------------------------------ fused classifier serving

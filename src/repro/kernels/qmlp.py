@@ -72,10 +72,13 @@ def _dequant(x, table, lo, scale, *, bits: int):
 
 
 def _mlp_forward(xq, w1, b1, w2, b2):
-    h = jnp.dot(xq, w1, preferred_element_type=jnp.float32)
-    h = jnp.maximum(h + b1[None, :], 0.0)
-    o = jnp.dot(h, w2, preferred_element_type=jnp.float32)
-    return o + b2[None, :]
+    """Biases arrive as (1, H) / (1, O) rows (see ``_bias_rows``)."""
+    h = jnp.dot(xq, w1, preferred_element_type=jnp.float32,
+                precision=envelope.MATMUL_PRECISION)
+    h = jnp.maximum(h + b1, 0.0)
+    o = jnp.dot(h, w2, preferred_element_type=jnp.float32,
+                precision=envelope.MATMUL_PRECISION)
+    return o + b2
 
 
 def _mlp_kernel(x_ref, table_ref, lo_ref, scale_ref, w1_ref, b1_ref, w2_ref,
@@ -90,8 +93,9 @@ def _svm_kernel(x_ref, table_ref, lo_ref, scale_ref, w_ref, b_ref, o_ref, *,
                 bits: int):
     xq = _dequant(x_ref[...].astype(jnp.float32), table_ref[...],
                   lo_ref[...], scale_ref[...], bits=bits)
-    o = jnp.dot(xq, w_ref[...], preferred_element_type=jnp.float32)
-    o_ref[...] = o + b_ref[...][None, :]
+    o = jnp.dot(xq, w_ref[...], preferred_element_type=jnp.float32,
+                precision=envelope.MATMUL_PRECISION)
+    o_ref[...] = o + b_ref[...]
 
 
 def _mlp_bank_kernel(x_ref, table_ref, lo_ref, scale_ref, w1_ref, b1_ref,
@@ -107,8 +111,9 @@ def _svm_bank_kernel(x_ref, table_ref, lo_ref, scale_ref, w_ref, b_ref,
                      o_ref, *, bits: int):
     xq = _dequant(x_ref[...].astype(jnp.float32), table_ref[0],
                   lo_ref[...], scale_ref[...], bits=bits)
-    o = jnp.dot(xq, w_ref[0], preferred_element_type=jnp.float32)
-    o_ref[0] = o + b_ref[0][None, :]
+    o = jnp.dot(xq, w_ref[0], preferred_element_type=jnp.float32,
+                precision=envelope.MATMUL_PRECISION)
+    o_ref[0] = o + b_ref[0]
 
 
 def _pad_batch(x, block_m: int):
@@ -160,15 +165,15 @@ def bespoke_mlp_pallas(x, table, w1, b1, w2, b2, *, bits: int,
             pl.BlockSpec((f, 2 ** bits), lambda i: (0, 0)),
             *_row_specs(f, 1),
             pl.BlockSpec((f, h), lambda i: (0, 0)),
-            pl.BlockSpec((h,), lambda i: (0,)),
+            pl.BlockSpec((1, h), lambda i: (0, 0)),
             pl.BlockSpec((h, o), lambda i: (0, 0)),
-            pl.BlockSpec((o,), lambda i: (0,)),
+            pl.BlockSpec((1, o), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, o), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0], o), jnp.float32),
         interpret=interpret,
     )(x, *_f32(table), jnp.asarray(lo), jnp.asarray(scale),
-      *_f32(w1, b1, w2, b2))
+      *_f32(w1), envelope.unit_rows(b1), *_f32(w2), envelope.unit_rows(b2))
     return out[:m]
 
 
@@ -195,12 +200,13 @@ def bespoke_svm_pallas(x, table, w, b, *, bits: int,
             pl.BlockSpec((f, 2 ** bits), lambda i: (0, 0)),
             *_row_specs(f, 1),
             pl.BlockSpec((f, o), lambda i: (0, 0)),
-            pl.BlockSpec((o,), lambda i: (0,)),
+            pl.BlockSpec((1, o), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, o), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0], o), jnp.float32),
         interpret=interpret,
-    )(x, *_f32(table), jnp.asarray(lo), jnp.asarray(scale), *_f32(w, b))
+    )(x, *_f32(table), jnp.asarray(lo), jnp.asarray(scale), *_f32(w),
+      envelope.unit_rows(b))
     return out[:m]
 
 
@@ -232,15 +238,15 @@ def bespoke_mlp_bank_pallas(x, tables, w1, b1, w2, b2, *, bits: int,
             pl.BlockSpec((1, f, 2 ** bits), lambda di, i: (di, 0, 0)),
             *_row_specs(f, 2),
             pl.BlockSpec((1, f, h), lambda di, i: (di, 0, 0)),
-            pl.BlockSpec((1, h), lambda di, i: (di, 0)),
+            pl.BlockSpec((1, 1, h), lambda di, i: (di, 0, 0)),
             pl.BlockSpec((1, h, o), lambda di, i: (di, 0, 0)),
-            pl.BlockSpec((1, o), lambda di, i: (di, 0)),
+            pl.BlockSpec((1, 1, o), lambda di, i: (di, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bm, o), lambda di, i: (di, i, 0)),
         out_shape=jax.ShapeDtypeStruct((d, x.shape[0], o), jnp.float32),
         interpret=interpret,
     )(x, *_f32(tables), jnp.asarray(lo), jnp.asarray(scale),
-      *_f32(w1, b1, w2, b2))
+      *_f32(w1), envelope.unit_rows(b1), *_f32(w2), envelope.unit_rows(b2))
     return out[:, :m]
 
 
@@ -269,10 +275,11 @@ def bespoke_svm_bank_pallas(x, tables, w, b, *, bits: int,
             pl.BlockSpec((1, f, 2 ** bits), lambda di, i: (di, 0, 0)),
             *_row_specs(f, 2),
             pl.BlockSpec((1, f, o), lambda di, i: (di, 0, 0)),
-            pl.BlockSpec((1, o), lambda di, i: (di, 0)),
+            pl.BlockSpec((1, 1, o), lambda di, i: (di, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bm, o), lambda di, i: (di, i, 0)),
         out_shape=jax.ShapeDtypeStruct((d, x.shape[0], o), jnp.float32),
         interpret=interpret,
-    )(x, *_f32(tables), jnp.asarray(lo), jnp.asarray(scale), *_f32(w, b))
+    )(x, *_f32(tables), jnp.asarray(lo), jnp.asarray(scale), *_f32(w),
+      envelope.unit_rows(b))
     return out[:, :m]

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import adc
+from repro.kernels.envelope import MATMUL_PRECISION
 
 
 def value_table(mask: jnp.ndarray, bits: int, vmin=0.0, vmax=1.0,
@@ -132,8 +133,8 @@ def bespoke_mlp_ref(x: jnp.ndarray, table: jnp.ndarray, bits: int,
     """Fused analog-frontend + printed-MLP forward:
     logits = relu(ADC(x) @ w1 + b1) @ w2 + b2."""
     xq = adc_quantize_ref(x, table, bits, vmin, vmax)
-    h = jax.nn.relu(xq @ w1 + b1)
-    return h @ w2 + b2
+    h = jax.nn.relu(jnp.dot(xq, w1, precision=MATMUL_PRECISION) + b1)
+    return jnp.dot(h, w2, precision=MATMUL_PRECISION) + b2
 
 
 def bespoke_svm_ref(x: jnp.ndarray, table: jnp.ndarray, bits: int,
@@ -141,7 +142,7 @@ def bespoke_svm_ref(x: jnp.ndarray, table: jnp.ndarray, bits: int,
                     vmin=0.0, vmax=1.0) -> jnp.ndarray:
     """Fused analog-frontend + linear-SVM forward: scores = ADC(x) @ w + b."""
     xq = adc_quantize_ref(x, table, bits, vmin, vmax)
-    return xq @ w + b
+    return jnp.dot(xq, w, precision=MATMUL_PRECISION) + b
 
 
 def bespoke_mlp_bank_ref(x: jnp.ndarray, tables: jnp.ndarray, bits: int,

@@ -22,8 +22,6 @@ import traceback
 from pathlib import Path
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -75,19 +73,19 @@ def lower_cell(cfg, shape, mesh, microbatches=None):
         fn = steps.make_train_step(cfg, mesh, shape,
                                    microbatches=specs["n_microbatches"])
         step_struct = jax.ShapeDtypeStruct((), jnp.int32)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, donate_argnums=(0,)).lower(
                 state, specs["batch"], step_struct)
         meta["n_microbatches"] = specs["n_microbatches"]
     elif shape.kind == "prefill":
         _, params = state_structs(cfg, mesh)
         fn = steps.make_prefill_step(cfg, mesh)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn).lower(params, specs["batch"])
     else:
         _, params = state_structs(cfg, mesh, inference=True)
         fn = steps.make_decode_step(cfg, mesh)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, donate_argnums=(2,)).lower(
                 params, specs["batch"], specs["cache"])
     compiled = lowered.compile()

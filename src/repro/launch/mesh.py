@@ -5,7 +5,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 
 
 def _auto(n):
@@ -16,12 +16,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     """Target TPU v5e topology: 16x16 = 256 chips per pod; 2 pods = 512."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=_auto(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over available devices (CPU smoke tests, examples)."""
-    return make_mesh((data, model), ("data", "model"), axis_types=_auto(2))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2))
 
 
 def describe(mesh) -> str:

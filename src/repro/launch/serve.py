@@ -10,8 +10,6 @@ import argparse
 import time
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 
@@ -66,7 +64,7 @@ def main(argv=None):
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = mesh_lib.make_host_mesh(1, 1)
     rng = np.random.default_rng(0)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = transformer.init_params(jax.random.PRNGKey(0), cfg)
         prefill = jax.jit(steps.make_prefill_step(cfg, mesh))
         decode = jax.jit(steps.make_decode_step(cfg, mesh),

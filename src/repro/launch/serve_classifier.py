@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import deploy
+from repro.launch import compile_cache
 
 
 def make_request_stream(x: np.ndarray, num_requests: int, request_size: int,
@@ -295,6 +296,7 @@ def build_parser():
 def main(argv=None):
     ap = build_parser()
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     from repro.data import tabular
     if args.smoke:
@@ -402,12 +404,12 @@ def main(argv=None):
         y_np = np.asarray(data["y_test"])[None, :]
         x_jnp = jnp.asarray(data["x_test"], jnp.float32)
         logits = np.asarray(nonideal_fn(x_jnp))
-        served = deploy._jnp_mean_acc(np.argmax(logits, -1) == y_np)
+        served = deploy.mean_acc(np.argmax(logits, -1) == y_np)
         recovered = None
         if cal_fn is not None:
             # calibration demonstration: the SAME measured instance,
             # served through the re-baked tables (DESIGN.md §15)
-            recovered = deploy._jnp_mean_acc(
+            recovered = deploy.mean_acc(
                 np.argmax(np.asarray(cal_fn(x_jnp)), -1) == y_np)
         for i, d in enumerate(designs):
             rec = (f" calibrated={recovered[i]:.3f} "

@@ -34,8 +34,6 @@ import argparse
 import time
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 
@@ -45,6 +43,7 @@ from repro.core.spec import parse_range
 from repro.configs.base import ShapeConfig
 from repro.data.lm import LMDataConfig, SyntheticLM
 from repro.distributed import fault
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_lib
 from repro.models import steps
 
@@ -344,6 +343,7 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv=None):
     ap = build_parser()
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.adc_search:
         return run_adc_search(args)
@@ -353,7 +353,7 @@ def main(argv=None):
     cfg, mesh, train_step, data = build(
         args.arch, smoke=args.smoke, seq=args.seq, batch=args.batch,
         microbatches=args.microbatches, steps_total=args.steps)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = steps.init_state(jax.random.PRNGKey(0), cfg, mesh)
         jstep = jax.jit(train_step, donate_argnums=(0,))
         ckpt = CheckpointManager(args.ckpt_dir, keep=2)

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import qat
+from repro.kernels.envelope import MATMUL_PRECISION
 
 Params = List[Tuple[jnp.ndarray, jnp.ndarray]]
 
@@ -38,12 +39,17 @@ def apply_mlp(params: Params, x: jnp.ndarray, dp: Optional[jnp.ndarray] = None,
         if dp is not None:
             w = qat.quantize_po2(w, dp, weight_bits)
             b = qat.quantize_fixed(b, dp, weight_bits)
-        h = h @ w + b
+        h = jnp.dot(h, w, precision=MATMUL_PRECISION) + b
         if i < n - 1:
             h = jax.nn.relu(h)
     return h
 
 
-def accuracy(params: Params, x, y, dp=None, weight_bits: int = 8) -> jnp.ndarray:
+def n_correct(params: Params, x, y, dp=None, weight_bits: int = 8
+              ) -> jnp.ndarray:
+    """Correctly classified samples, as an int32 count. The accuracy is
+    this count over ``len(y)``, divided on the host (search.accuracy):
+    a device-side f32 mean rounds differently under vmap, jit and
+    backend, which breaks bit-for-bit equality between engines."""
     logits = apply_mlp(params, x, dp, weight_bits)
-    return (jnp.argmax(logits, -1) == y).mean()
+    return jnp.sum(jnp.argmax(logits, -1) == y, dtype=jnp.int32)

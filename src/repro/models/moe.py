@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 
 from repro.configs.base import MoEConfig
 
@@ -181,7 +180,7 @@ def moe_ffn_decode(x: jnp.ndarray, params: Dict, m: MoEConfig, mesh
     def wrapped(xf, router_w, wi, wg, wo):
         return body(xf, router_w, wi, wg, wo)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         wrapped, mesh=mesh,
         in_specs=(P(None, None),                       # tokens replicated
                   P(None, None),                       # router replicated
@@ -203,7 +202,7 @@ def moe_ffn(x: jnp.ndarray, params: Dict, m: MoEConfig, mesh
     dp = _dp_axes(mesh)
     tp = mesh.shape["model"]
     body = partial(_local_moe, m=m, tp=tp, model_axis="model", dp_axes=dp)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp if dp else None, None, None),   # x over batch
                   P(None, None),                        # router replicated

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import shard_map
 
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.distributed import sharding as sh
@@ -115,7 +114,7 @@ def make_train_step(cfg: ArchConfig, mesh, shape: ShapeConfig,
             bspec = jax.tree_util.tree_map(
                 lambda _: P(None, dp if len(dp) > 1 else dp[0], *()), batch)
             errspec = P(dp if len(dp) > 1 else dp[0], None)
-            grads, new_err, loss = shard_map(
+            grads, new_err, loss = jax.shard_map(
                 local, mesh=mesh,
                 in_specs=(pspec, errspec, bspec),
                 out_specs=(pspec, errspec, P()),

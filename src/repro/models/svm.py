@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import qat
+from repro.kernels.envelope import MATMUL_PRECISION
 
 Params = Tuple[jnp.ndarray, jnp.ndarray]      # (W: (F, C), b: (C,))
 
@@ -26,7 +27,7 @@ def apply_svm(params: Params, x: jnp.ndarray,
     if dp is not None:
         w = qat.quantize_po2(w, dp, weight_bits)
         b = qat.quantize_fixed(b, dp, weight_bits)
-    return x @ w + b
+    return jnp.dot(x, w, precision=MATMUL_PRECISION) + b
 
 
 def svm_loss(params: Params, x, y, dp=None, margin: float = 1.0,
@@ -39,6 +40,8 @@ def svm_loss(params: Params, x, y, dp=None, margin: float = 1.0,
     return (hinge ** 2).mean() + l2 * jnp.sum(params[0] ** 2)
 
 
-def accuracy(params: Params, x, y, dp=None, weight_bits: int = 8
-             ) -> jnp.ndarray:
-    return (jnp.argmax(apply_svm(params, x, dp, weight_bits), -1) == y).mean()
+def n_correct(params: Params, x, y, dp=None, weight_bits: int = 8
+              ) -> jnp.ndarray:
+    """Correctly classified samples, int32 (see mlp.n_correct)."""
+    scores = apply_svm(params, x, dp, weight_bits)
+    return jnp.sum(jnp.argmax(scores, -1) == y, dtype=jnp.int32)

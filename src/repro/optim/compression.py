@@ -25,9 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import shard_map  # noqa: F401  (re-export: callers wrap
-# these collectives in a shard_map manual over ('pod','data'); import it
-# from here so the jax-version shim in repro.compat applies everywhere)
 
 
 def _quant(x):

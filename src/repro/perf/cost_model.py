@@ -71,13 +71,27 @@ MACHINE_MODELS: Dict[str, MachineModel] = {
 }
 
 
+# device_kind strings of the chip the "tpu" row describes
+TPU_V5E_KINDS = ("TPU v5 lite", "TPU v5e")
+
+
 def machine_model(backend: Optional[str] = None) -> MachineModel:
     """The machine model for ``backend`` (default: the active jax
-    backend; unknown backends get the conservative cpu row)."""
+    backend). A backend without a row, or an active TPU that is not a
+    v5e, raises: pricing a device with another device's peaks would
+    make every estimate silently wrong."""
     if backend is None:
         import jax
         backend = jax.default_backend()
-    return MACHINE_MODELS.get(backend, MACHINE_MODELS["cpu"])
+        kind = jax.devices()[0].device_kind
+        if backend == "tpu" and kind not in TPU_V5E_KINDS:
+            raise ValueError(f"no machine peaks for TPU device kind "
+                             f"{kind!r} (known: {TPU_V5E_KINDS})")
+    try:
+        return MACHINE_MODELS[backend]
+    except KeyError:
+        raise ValueError(f"no machine peaks for backend {backend!r} "
+                         f"(known: {sorted(MACHINE_MODELS)})") from None
 
 
 @dataclasses.dataclass(frozen=True)

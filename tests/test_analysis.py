@@ -2,8 +2,6 @@
 must track analytic model FLOPs, and multipliers must recover scan trip
 counts (the whole §Roofline methodology rests on this)."""
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -68,7 +66,7 @@ def test_end_to_end_vs_6nd():
     cfg = smoke_config("deepseek-7b").replace(num_layers=3)
     mesh = make_host_mesh(1, 1)
     shape = ShapeConfig("t", 64, 4, "train")
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = steps.init_state(jax.random.PRNGKey(0), cfg, mesh)
         fn = steps.make_train_step(cfg, mesh, shape, microbatches=2)
         specs = steps.input_specs(cfg, shape, mesh, microbatches=2)

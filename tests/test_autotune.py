@@ -123,6 +123,24 @@ def test_tuned_block_m_changes_speed_not_values():
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+def test_dispatch_logs_the_heuristic_tile_it_used(caplog):
+    """Without a tuned entry (e.g. a table tuned on another backend) the
+    log names the VMEM-heuristic tile the kernel runs with."""
+    dispatch.set_tuned_policy(None)
+    spec = AdcSpec(bits=3)
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.random((32, 4)), jnp.float32)
+    mask = adc.repair_mask(jnp.asarray(
+        (rng.random((4, 8)) < 0.6).astype(np.int32)))
+    dispatch._LOGGED.clear()
+    with caplog.at_level(logging.INFO, logger="repro.kernels.dispatch"):
+        dispatch.dispatch("adc_quantize", x, spec.value_table(mask),
+                          spec=spec, interpret=True)
+    want = cost_model.heuristic_block_m(
+        Workload("adc_quantize", m=32, c=4, bits=3))
+    assert f"block_m={want}:heuristic" in caplog.text
+
+
 def test_unmatched_shape_class_falls_back_to_heuristic():
     table = autotune.tune([W_ADC], measure_fn=_meas(16), backend="cpu")
     dispatch.set_tuned_policy(autotune.TablePolicy(table))

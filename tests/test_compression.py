@@ -36,19 +36,18 @@ _SUBPROC = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import AxisType, make_mesh, shard_map
     from repro.optim import compression
 
-    mesh = make_mesh((8,), ("data",),
-                     axis_types=(AxisType.Auto,))
+    mesh = jax.make_mesh((8,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     rng = np.random.default_rng(0)
     xs = rng.normal(size=(8, 1000)).astype(np.float32)
 
     def body(x):
         return compression.ring_allreduce_int8(x[0], "data", 8) / 8.0
 
-    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("data", None),
-                           out_specs=P(None), check_vma=False))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data", None),
+                               out_specs=P(None), check_vma=False))
     got = np.asarray(fn(jnp.asarray(xs)))
     want = xs.mean(0)
     scale = np.abs(xs).max() / 127.0
@@ -65,7 +64,7 @@ _SUBPROC = textwrap.dedent("""
             out, ne = compression.sync_grads({"w": gv[0]}, ev[0],
                                              ("data",), (8,))
             return out["w"], ne[None]
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             b, mesh=mesh, in_specs=(P("data", None), P("data", None)),
             out_specs=(P(None), P("data", None)), check_vma=False))
         return f(g, err)

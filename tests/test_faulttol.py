@@ -238,7 +238,7 @@ def test_calibrated_bank_matches_calibrate_front():
     x = jnp.asarray(data["x_test"], jnp.float32)
     y = np.asarray(data["y_test"])
     fn = deploy.make_calibrated_bank_fn(designs, ni, instance=1, samples=3)
-    acc_kernel = deploy._jnp_mean_acc(
+    acc_kernel = deploy.mean_acc(
         np.argmax(np.asarray(fn(x)), -1) == y[None, :])
     cal = deploy.calibrate_front(designs, ni, instance=1, samples=3)
     acc_rebaked = deploy.served_accuracies(cal, data["x_test"], y)

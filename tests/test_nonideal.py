@@ -295,7 +295,7 @@ def test_nonideal_bank_fn_reproduces_report_instance():
                                      SEEDS["y_test"], samples=S)
     fn = deploy.make_nonideal_bank_fn(designs, ni, instance=k, samples=S)
     logits = np.asarray(fn(jnp.asarray(SEEDS["x_test"], jnp.float32)))
-    served = deploy._jnp_mean_acc(
+    served = deploy.mean_acc(
         np.argmax(logits, -1) == np.asarray(SEEDS["y_test"])[None, :])
     want = np.array([d["instance_accuracies"][k] for d in rep["designs"]])
     np.testing.assert_array_equal(served.astype(np.float64), want)

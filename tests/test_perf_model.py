@@ -147,7 +147,8 @@ def test_roofline_record_shape():
 
 def test_machine_model_lookup():
     assert cost_model.machine_model("tpu").name == "tpu-v5e"
-    assert cost_model.machine_model("no-such-backend").name == "cpu-host"
+    with pytest.raises(ValueError, match="no machine peaks"):
+        cost_model.machine_model("no-such-backend")
     active = cost_model.machine_model()
     assert active.peak_flops > 0 and active.hbm_bw > 0
 

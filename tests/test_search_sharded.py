@@ -113,13 +113,12 @@ _SUBPROC = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     import numpy as np, jax
-    from repro.compat import AxisType, make_mesh
     from repro.core import search, nsga2
     from repro.data import tabular
 
     assert len(jax.devices()) == 2, jax.devices()
-    mesh = make_mesh((2, 1), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((2, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     data = tabular.make_dataset("seeds")
     sizes = (7, 4, 3)
     cfg = search.SearchConfig(bits=2, pop_size=6, generations=1,

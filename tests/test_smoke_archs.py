@@ -2,8 +2,6 @@
 forward/train step + prefill/decode on CPU, asserting shapes and no NaNs.
 (The FULL configs are exercised via the dry-run only.)"""
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -47,7 +45,7 @@ def test_forward_and_train_step(arch, mesh):
     b, s = 2, 16
     batch = _batch_for(cfg, b, s)
     state = steps.init_state(jax.random.PRNGKey(0), cfg, mesh)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         loss, metrics = transformer.loss_fn(state.params, batch, cfg, mesh)
         assert np.isfinite(float(loss)), (arch, float(loss))
         shape = ShapeConfig("smoke", s, b, "train")
@@ -71,7 +69,7 @@ def test_prefill_decode_consistency(arch, mesh):
     b, s = 2, 16
     batch = _batch_for(cfg, b, s, kind="prefill")
     params = transformer.init_params(jax.random.PRNGKey(0), cfg)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits, cache = serving.prefill(params, batch, cfg, mesh)
         assert logits.shape == (b, cfg.vocab_size)
         assert bool(jnp.isfinite(logits).all()), arch

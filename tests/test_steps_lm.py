@@ -6,8 +6,6 @@
 * checkpoint restore resumes training bit-identically.
 """
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -27,7 +25,7 @@ def test_train_loss_decreases(mesh):
     cfg, mesh_, train_step, data = build("deepseek-7b", smoke=True, seq=64,
                                          batch=8, microbatches=2,
                                          steps_total=30)
-    with compat.set_mesh(mesh_):
+    with jax.set_mesh(mesh_):
         state = steps.init_state(jax.random.PRNGKey(0), cfg, mesh_)
         jstep = jax.jit(train_step, donate_argnums=(0,))
         losses = []
@@ -50,7 +48,7 @@ def test_prefill_matches_forward(arch, mesh):
     pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     batch = {"tokens": tokens, "positions": pos}
     params = transformer.init_params(jax.random.PRNGKey(1), cfg)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         full = transformer.logits_fn(params, batch, cfg, mesh)      # (b,s,V)
         pre, cache = serving.prefill(params, batch, cfg, mesh)      # (b,V)
     np.testing.assert_allclose(np.asarray(pre), np.asarray(full[:, -1]),
@@ -67,7 +65,7 @@ def test_decode_matches_teacher_forcing(arch, mesh):
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (b, s + 1)), jnp.int32)
     pos_full = jnp.broadcast_to(jnp.arange(s + 1, dtype=jnp.int32), (b, s + 1))
     params = transformer.init_params(jax.random.PRNGKey(1), cfg)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         want = transformer.logits_fn(
             params, {"tokens": toks, "positions": pos_full}, cfg, mesh)[:, -1]
         _, cache = serving.prefill(
@@ -85,7 +83,7 @@ def test_checkpoint_resume_bitwise(tmp_path, mesh):
     cfg, mesh_, train_step, data = build("phi3-mini-3.8b", smoke=True,
                                          seq=32, batch=4, microbatches=1,
                                          steps_total=10)
-    with compat.set_mesh(mesh_):
+    with jax.set_mesh(mesh_):
         jstep = jax.jit(train_step)
         s0 = steps.init_state(jax.random.PRNGKey(0), cfg, mesh_)
         # straight run: 6 steps
