@@ -1,0 +1,8 @@
+"""Median host time a robustness call spends assembling its report (the
+``deploy.report`` span), from the program's ring over the whole window.
+Layer: robustness analysis. Moves ``mc_evals_per_s``."""
+from bench.lib.program import window_ms
+
+
+def read(obs):
+    return window_ms(obs, "deploy.evaluate_robustness", ("deploy.report",))

@@ -33,6 +33,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from repro import obs
 from repro.checkpoint.manager import CheckpointManager, pack_json, unpack_json
 from repro.core import area, qat
 from repro.core import nonideal as nonideal_lib
@@ -476,7 +477,11 @@ def _mc_instance_accuracies(designs: Sequence[DeployedClassifier],
     population entry (one (D, S, M/bm) launch); each view is re-scored by
     the design's baked classifier with the same vmap structure (design
     axis outer, instance axis inner) as the in-search objective, keeping
-    the search -> deploy robustness numbers bit-for-bit reproducible."""
+    the search -> deploy robustness numbers bit-for-bit reproducible.
+
+    Spans: ``deploy.mc_operands`` (masks, the draw, the interval
+    operands), ``deploy.mc_launch`` (the MC population dispatch) and
+    ``deploy.rescore`` (the vmapped count, and the wait for it)."""
     import jax
     import jax.numpy as jnp
 
@@ -485,43 +490,49 @@ def _mc_instance_accuracies(designs: Sequence[DeployedClassifier],
     from repro.models import svm as svm_lib
     d0 = designs[0]
     spec = d0.spec
-    masks = jnp.stack([jnp.asarray(d.mask, jnp.int32) for d in designs])
-    xj = jnp.asarray(np.asarray(x, np.float32))
-    yj = jnp.asarray(np.asarray(y))
-    # a §15 fault-tolerant front (TMR/calibrate provenance, or an
-    # explicit RedundantDraws stream) evaluates through the
-    # calibrated-table entry: redundancy folds into the draw stream and
-    # calibrated designs reconstruct through per-instance re-baked
-    # tables — op-for-op the in-search FT objective
-    ft = (isinstance(draws, ft_redundancy.RedundantDraws)
-          or any(d.tmr is not None or d.calibrated for d in designs))
-    if ft:
-        if draws is None:
-            draws = ft_redundancy.draw_redundant(
-                spec.bits, masks.shape[1], samples if samples else 32,
-                nonideal)
-        tmr = jnp.stack([
-            jnp.zeros(masks.shape[1], jnp.int32) if d.tmr is None
-            else jnp.asarray(d.tmr, jnp.int32) for d in designs])
-        cal = jnp.asarray([int(d.calibrated) for d in designs], jnp.int32)
-        ops_ft = faulttol_cal.mc_operands_ft(spec, nonideal, masks, tmr,
-                                             cal, draws)
-        xq_mc = dispatch.dispatch("mc_eval_cal_population", xj, *ops_ft,
-                                  spec=spec, interpret=interpret)
-    else:
-        if draws is None:
-            draws = nonideal_lib.draw(spec.bits, masks.shape[1],
-                                      samples if samples else 32, nonideal)
-        mc = nonideal_lib.mc_operands(spec, nonideal, masks, draws=draws)
-        xq_mc = dispatch.dispatch("mc_eval_population", xj, *mc, spec=spec,
+    with obs.span("deploy.mc_operands"):
+        masks = jnp.stack([jnp.asarray(d.mask, jnp.int32) for d in designs])
+        xj = jnp.asarray(np.asarray(x, np.float32))
+        yj = jnp.asarray(np.asarray(y))
+        # a §15 fault-tolerant front (TMR/calibrate provenance, or an
+        # explicit RedundantDraws stream) evaluates through the
+        # calibrated-table entry: redundancy folds into the draw stream
+        # and calibrated designs reconstruct through per-instance
+        # re-baked tables — op-for-op the in-search FT objective
+        ft = (isinstance(draws, ft_redundancy.RedundantDraws)
+              or any(d.tmr is not None or d.calibrated for d in designs))
+        if ft:
+            if draws is None:
+                draws = ft_redundancy.draw_redundant(
+                    spec.bits, masks.shape[1], samples if samples else 32,
+                    nonideal)
+            tmr = jnp.stack([
+                jnp.zeros(masks.shape[1], jnp.int32) if d.tmr is None
+                else jnp.asarray(d.tmr, jnp.int32) for d in designs])
+            cal = jnp.asarray([int(d.calibrated) for d in designs],
+                              jnp.int32)
+            entry = "mc_eval_cal_population"
+            operands = faulttol_cal.mc_operands_ft(spec, nonideal, masks,
+                                                   tmr, cal, draws)
+        else:
+            if draws is None:
+                draws = nonideal_lib.draw(spec.bits, masks.shape[1],
+                                          samples if samples else 32,
+                                          nonideal)
+            entry = "mc_eval_population"
+            operands = nonideal_lib.mc_operands(spec, nonideal, masks,
+                                                draws=draws)
+    with obs.span("deploy.mc_launch"):
+        xq_mc = dispatch.dispatch(entry, xj, *operands, spec=spec,
                                   interpret=interpret)   # (D, S, M, C)
     count = svm_lib.n_correct if d0.kind == "svm" else mlp_lib.n_correct
     # dp=None: the baked weights are already po2/fixed-quantized at
     # export; re-quantization would be a no-op by construction and the
     # in-graph path was only ever there for traced search-time dp
     per_design = lambda p, xq_s: jax.vmap(lambda xq: count(p, xq, yj))(xq_s)
-    correct = jax.vmap(per_design)(_stacked_model_params(designs), xq_mc)
-    return accuracy(correct, len(yj))
+    with obs.span("deploy.rescore"):
+        correct = jax.vmap(per_design)(_stacked_model_params(designs), xq_mc)
+        return accuracy(correct, len(yj))
 
 
 def evaluate_robustness(designs: Sequence[DeployedClassifier],
@@ -542,10 +553,28 @@ def evaluate_robustness(designs: Sequence[DeployedClassifier],
     the per-instance accuracies, and the *yield*: the fraction of
     instances within each ``yield_margins`` accuracy drop of the exported
     value (the arXiv:2602.10790 question — how many manufactured devices
-    still classify acceptably)."""
+    still classify acceptably).
+
+    One call is one ``deploy.evaluate_robustness`` span (args
+    ``designs``, ``samples``; counter ``deploy.instances``), the report's
+    assembly its ``deploy.report`` child (DESIGN.md §16)."""
     designs = list(designs)
-    mc_accs = _mc_instance_accuracies(designs, nonideal, x, y, draws=draws,
-                                      samples=samples, interpret=interpret)
+    with obs.span("deploy.evaluate_robustness", designs=len(designs),
+                  samples=samples):
+        mc_accs = _mc_instance_accuracies(designs, nonideal, x, y,
+                                          draws=draws, samples=samples,
+                                          interpret=interpret)
+        obs.count("deploy.instances", mc_accs.size)
+        with obs.span("deploy.report"):
+            return _robustness_report(designs, nonideal, mc_accs,
+                                      yield_margins)
+
+
+def _robustness_report(designs: List[DeployedClassifier],
+                       nonideal: NonIdealSpec, mc_accs: np.ndarray,
+                       yield_margins: Tuple[float, ...]) -> Dict:
+    """``evaluate_robustness``'s JSON-able report from the (D, S) instance
+    accuracies."""
     exported = np.array([d.accuracy for d in designs])
     expected = nonideal_lib.robust_objective(exported, mc_accs, "expected")
     worst = nonideal_lib.robust_objective(exported, mc_accs, "worst")

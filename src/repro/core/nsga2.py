@@ -23,6 +23,8 @@ from typing import Callable, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
+
 
 def fast_non_dominated_sort(F: np.ndarray) -> np.ndarray:
     """Pareto rank (0 = front) for fitness matrix F (P, M), minimization."""
@@ -95,15 +97,17 @@ def init_state(eval_fn: Callable[[np.ndarray], np.ndarray],
                pop_size: int = 32,
                seed: int = 0,
                init: Optional[np.ndarray] = None) -> EvolveState:
-    """Draw (or adopt) the initial population and evaluate it."""
-    rng = np.random.default_rng(seed)
-    if init is None:
-        pop = (rng.random((pop_size, genome_len)) < 0.5).astype(np.uint8)
-        pop[0] = 1                                   # seed the full (unpruned) design
-    else:
-        pop = init.astype(np.uint8).copy()
-    fit = np.asarray(eval_fn(pop), np.float64)
-    return EvolveState(pop, fit, 0, rng)
+    """Draw (or adopt) the initial population and evaluate it (span
+    ``nsga2.initial``)."""
+    with obs.span("nsga2.initial"):
+        rng = np.random.default_rng(seed)
+        if init is None:
+            pop = (rng.random((pop_size, genome_len)) < 0.5).astype(np.uint8)
+            pop[0] = 1                # seed the full (unpruned) design
+        else:
+            pop = init.astype(np.uint8).copy()
+        fit = np.asarray(eval_fn(pop), np.float64)
+        return EvolveState(pop, fit, 0, rng)
 
 
 def evolve_step(state: EvolveState,
@@ -126,36 +130,45 @@ def evolve_step(state: EvolveState,
     RNG draw has the historical shape, so the unscreened stream stays
     bit-identical. ``on_evaluated(genomes, fitness)`` fires after each
     evaluation with the true (genome, fitness) pairs — the surrogate's
-    online-training feed."""
-    pop, fit, rng = state.pop, state.fit, state.rng
-    pop_size, glen = pop.shape
-    n_off = pop_size * max(int(offspring_factor), 1)
-    if pm_bit is None:
-        pm_bit = pm / max(np.sqrt(glen), 1.0)
-    rank = fast_non_dominated_sort(fit)
-    dist = crowding_distance(fit, rank)
-    parents_a = _tournament(rng, rank, dist, n=None if n_off == pop_size else n_off)
-    parents_b = _tournament(rng, rank, dist, n=None if n_off == pop_size else n_off)
-    xa, xb = pop[parents_a], pop[parents_b]
-    do_x = (rng.random((n_off, 1)) < pc)
-    mix = rng.random((n_off, glen)) < 0.5
-    child = np.where(do_x & mix, xb, xa)
-    flip = rng.random((n_off, glen)) < pm_bit
-    child = np.where(flip, 1 - child, child).astype(np.uint8)
-    if screen_fn is not None and n_off > pop_size:
-        keep = np.asarray(screen_fn(child)).reshape(-1)[:pop_size]
-        child = child[keep]
-    cfit = np.asarray(eval_fn(child), np.float64)
-    if on_evaluated is not None:
-        on_evaluated(child, cfit)
-    # (mu + lambda) elitist survival
-    allpop = np.concatenate([pop, child])
-    allfit = np.concatenate([fit, cfit])
-    r = fast_non_dominated_sort(allfit)
-    d = crowding_distance(allfit, r)
-    order = np.lexsort((-d, r))
-    keep = order[:pop_size]
-    return EvolveState(allpop[keep], allfit[keep], state.generation + 1, rng)
+    online-training feed.
+
+    Spans: ``nsga2.generation`` around it all, ``nsga2.variation``
+    (sort, crowding, tournaments, crossover, mutation) and
+    ``nsga2.survival``; the evaluation opens its own."""
+    with obs.span("nsga2.generation"):
+        pop, fit, rng = state.pop, state.fit, state.rng
+        pop_size, glen = pop.shape
+        n_off = pop_size * max(int(offspring_factor), 1)
+        if pm_bit is None:
+            pm_bit = pm / max(np.sqrt(glen), 1.0)
+        with obs.span("nsga2.variation"):
+            rank = fast_non_dominated_sort(fit)
+            dist = crowding_distance(fit, rank)
+            n = None if n_off == pop_size else n_off
+            parents_a = _tournament(rng, rank, dist, n=n)
+            parents_b = _tournament(rng, rank, dist, n=n)
+            xa, xb = pop[parents_a], pop[parents_b]
+            do_x = (rng.random((n_off, 1)) < pc)
+            mix = rng.random((n_off, glen)) < 0.5
+            child = np.where(do_x & mix, xb, xa)
+            flip = rng.random((n_off, glen)) < pm_bit
+            child = np.where(flip, 1 - child, child).astype(np.uint8)
+        if screen_fn is not None and n_off > pop_size:
+            keep = np.asarray(screen_fn(child)).reshape(-1)[:pop_size]
+            child = child[keep]
+        cfit = np.asarray(eval_fn(child), np.float64)
+        if on_evaluated is not None:
+            on_evaluated(child, cfit)
+        with obs.span("nsga2.survival"):
+            # (mu + lambda) elitist survival
+            allpop = np.concatenate([pop, child])
+            allfit = np.concatenate([fit, cfit])
+            r = fast_non_dominated_sort(allfit)
+            d = crowding_distance(allfit, r)
+            order = np.lexsort((-d, r))
+            keep = order[:pop_size]
+            return EvolveState(allpop[keep], allfit[keep],
+                               state.generation + 1, rng)
 
 
 def evolve(eval_fn: Callable[[np.ndarray], np.ndarray],

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec
 
+from repro import obs
 from repro.core import adc, area, nsga2
 from repro.core import nonideal as nonideal_lib
 from repro.core.nonideal import NonIdealSpec
@@ -552,11 +553,12 @@ def _train_and_score_jit():
 
 def _stacked_init(pop: int, sizes, cfg: SearchConfig):
     """P copies of the shared initial (params, opt) pytrees, materialized
-    so the jit can donate them."""
-    params, opt = _init_model(sizes, cfg)
-    tile = lambda a: jnp.tile(a[None], (pop,) + (1,) * a.ndim)
-    return (jax.tree_util.tree_map(tile, params),
-            jax.tree_util.tree_map(tile, opt))
+    so the jit can donate them (span ``search.init``)."""
+    with obs.span("search.init"):
+        params, opt = _init_model(sizes, cfg)
+        tile = lambda a: jnp.tile(a[None], (pop,) + (1,) * a.ndim)
+        return (jax.tree_util.tree_map(tile, params),
+                jax.tree_util.tree_map(tile, opt))
 
 
 def search_draws(cfg: SearchConfig, channels: int):
@@ -675,7 +677,8 @@ def _dedup_bucket(unique: int, pop: int) -> int:
     return min(b, pop)
 
 
-def _eval_dedup(genomes: np.ndarray, cfg: SearchConfig, core) -> Dict:
+def _eval_dedup(genomes: np.ndarray, cfg: SearchConfig, core, args: Dict
+                ) -> Dict:
     """Exact-duplicate genome dedup around a population evaluation:
     ``core`` maps a (B, G) uint8 batch to a dict of (B, ...) arrays.
     Duplicates share one QAT lane; the unique set pads (by repeating row
@@ -683,20 +686,46 @@ def _eval_dedup(genomes: np.ndarray, cfg: SearchConfig, core) -> Dict:
     inverse index. Bit-identical to evaluating the full population:
     every vmapped QAT lane is a pure function of its own genome (the
     PR 3 contract ``train_pareto_front`` pins), so neither the sharing
-    nor the padding changes any individual's fitness."""
+    nor the padding changes any individual's fitness. ``args`` (the
+    enclosing span's) is given ``unique`` (where dedup ran) and
+    ``lanes``, the batch ``core`` ran at."""
     genomes = np.asarray(genomes, np.uint8)
+    args["lanes"] = len(genomes)
     if not cfg.dedup or len(genomes) <= 1:
         return core(genomes)
-    uniq, inverse = np.unique(genomes, axis=0, return_inverse=True)
-    if len(uniq) == len(genomes):
+    with obs.span("search.dedup"):
+        uniq, inverse = np.unique(genomes, axis=0, return_inverse=True)
+        args["unique"] = n_uniq = len(uniq)
+        bucket = _dedup_bucket(n_uniq, len(genomes))
+        if bucket > n_uniq:
+            uniq = np.concatenate(
+                [uniq, np.repeat(uniq[:1], bucket - n_uniq, axis=0)])
+    if n_uniq == len(genomes):
         return core(genomes)
-    bucket = _dedup_bucket(len(uniq), len(genomes))
-    if bucket > len(uniq):
-        uniq = np.concatenate(
-            [uniq, np.repeat(uniq[:1], bucket - len(uniq), axis=0)])
+    args["lanes"] = bucket
     out = core(uniq)
     inverse = np.asarray(inverse).reshape(-1)
     return {k: np.asarray(v)[inverse] for k, v in out.items()}
+
+
+def _evaluate(genomes: np.ndarray, data: Dict, sizes, cfg: SearchConfig,
+              core) -> np.ndarray:
+    """Dedup, ``core`` and the fitness columns of one population: the
+    ``search.evaluate`` span (args ``individuals``, ``unique``, ``lanes``;
+    counters ``search.individuals``, ``search.lanes``)."""
+    genomes = np.asarray(genomes, np.uint8)
+    with obs.span("search.evaluate", individuals=len(genomes)) as args:
+        out = _eval_dedup(genomes, cfg, core, args)
+        obs.count("search.individuals", len(genomes))
+        obs.count("search.lanes", args["lanes"])
+        return _fitness(out, genomes, data, sizes, cfg)
+
+
+def _fetch(out: Dict) -> Dict[str, np.ndarray]:
+    """The compiled generation's outputs on the host (span
+    ``search.fetch``: the host waits for the device here)."""
+    with obs.span("search.fetch"):
+        return {k: np.asarray(v) for k, v in out.items()}
 
 
 def evaluate_population(genomes: np.ndarray, data: Dict, sizes,
@@ -714,23 +743,26 @@ def evaluate_population(genomes: np.ndarray, data: Dict, sizes,
 
     def core(g):
         params0, opt0 = _stacked_init(len(g), sizes, cfg)
-        out = _train_and_score_jit()(
-            jnp.asarray(g, jnp.uint8), params0, opt0, dev_data,
-            tuple(sizes), cfg, draws=draws)
-        return {k: np.asarray(v) for k, v in out.items()}
+        with obs.span("search.launch"):
+            out = _train_and_score_jit()(
+                jnp.asarray(g, jnp.uint8), params0, opt0, dev_data,
+                tuple(sizes), cfg, draws=draws)
+        return _fetch(out)
 
-    return _fitness(_eval_dedup(genomes, cfg, core), genomes, data, sizes,
-                    cfg)
+    return _evaluate(genomes, data, sizes, cfg, core)
 
 
 def _fitness(out: Dict, genomes: np.ndarray, data: Dict, sizes,
              cfg: SearchConfig) -> np.ndarray:
     """Correct counts (+ MC counts) -> the minimized fitness columns
     [1 - accuracy, normalized area(, robustness)], reduced on the host
-    in f64 — shared by every engine."""
+    in f64 — shared by every engine. Area pricing is the ``search.areas``
+    span."""
     n = len(data["y_test"])
     accs = accuracy(out["correct"], n)
-    cols = [1.0 - accs, population_areas(genomes, sizes[0], cfg)]
+    with obs.span("search.areas"):
+        areas = population_areas(genomes, sizes[0], cfg)
+    cols = [1.0 - accs, areas]
     if "mc_correct" in out:
         cols.append(nonideal_lib.robust_objective(
             accs, accuracy(out["mc_correct"], n), cfg.robust_objective,
@@ -797,18 +829,18 @@ def evaluate_population_sharded(genomes: np.ndarray, data: Dict, sizes,
     def core(g):
         axes = sharding_lib.population_axes(mesh, len(g))
         params0, opt0 = _stacked_init(len(g), sizes, cfg)
-        if axes is None:
-            out = _train_and_score_jit()(
-                jnp.asarray(g, jnp.uint8), params0, opt0, dev_data,
-                tuple(sizes), cfg, draws=draws)
-        else:
-            fn = _sharded_train_and_score(mesh, axes, tuple(sizes), cfg)
-            out = fn(jnp.asarray(g, jnp.uint8), params0, opt0, dev_data,
-                     draws)
-        return {k: np.asarray(v) for k, v in out.items()}
+        with obs.span("search.launch"):
+            if axes is None:
+                out = _train_and_score_jit()(
+                    jnp.asarray(g, jnp.uint8), params0, opt0, dev_data,
+                    tuple(sizes), cfg, draws=draws)
+            else:
+                fn = _sharded_train_and_score(mesh, axes, tuple(sizes), cfg)
+                out = fn(jnp.asarray(g, jnp.uint8), params0, opt0, dev_data,
+                         draws)
+        return _fetch(out)
 
-    return _fitness(_eval_dedup(genomes, cfg, core), genomes, data, sizes,
-                    cfg)
+    return _evaluate(genomes, data, sizes, cfg, core)
 
 
 @functools.partial(jax.jit, static_argnames=("sizes", "cfg"))
@@ -977,64 +1009,69 @@ def run_search(data: Dict, sizes, cfg: SearchConfig,
     ``init`` seeds the initial population ((pop_size, G) uint8) instead
     of the random draw — e.g. embedding an ADC-only front into the
     co-search space so its points are guaranteed candidates (the
-    cosearch_stream benchmark's ε-dominance anchor)."""
+    cosearch_stream benchmark's ε-dominance anchor).
+
+    An evolutionary search is one ``search.run`` span (DESIGN.md §16)."""
     if cfg.engine == "gradient":
         return run_gradient_search(data, sizes, cfg, log=log, ckpt=ckpt,
                                    resume=resume,
                                    return_trained=return_trained)
-    from repro.core import surrogate as surrogate_lib
-    C = sizes[0]
-    cfg.adc_spec.validate_channels(C)   # per-channel ranges must match data
-    _validate_frontend(data, sizes, cfg)
-    G = genome_len(C, cfg.bits, cfg.frontend, cfg.faulttol)
-    screened = cfg.screen_factor > 1
-    sur = [surrogate_lib.init(G, cfg.n_objectives,
-                              hidden=cfg.surrogate_hidden,
-                              seed=cfg.seed)] if screened else [None]
-    state = None
-    if ckpt is not None and resume:
-        step = ckpt.latest_step()
-        if step is not None:
-            restored = restore_search_state(
-                ckpt, step, cfg.pop_size, G, n_obj=cfg.n_objectives,
-                surrogate_like=sur[0] if screened else None)
-            if screened:
-                state, sur[0] = restored
-            else:
-                state = restored
-    on_gen = None
-    if ckpt is not None:
-        # blocking: the state is a few KB and the atomic-commit rename must
-        # land before the next generation can be declared done.
-        on_gen = lambda st: ckpt.save(
-            st.generation, search_state_tree(st, sur[0]), blocking=True)
-    screen_fn = on_eval = None
-    if screened:
-        def on_eval(genomes, fitness):
-            sur[0] = surrogate_lib.observe(sur[0], genomes, fitness,
-                                           steps=cfg.surrogate_steps)
+    with obs.span("search.run", pop=cfg.pop_size,
+                  generations=cfg.generations):
+        from repro.core import surrogate as surrogate_lib
+        C = sizes[0]
+        cfg.adc_spec.validate_channels(C)   # per-channel ranges match data
+        _validate_frontend(data, sizes, cfg)
+        G = genome_len(C, cfg.bits, cfg.frontend, cfg.faulttol)
+        screened = cfg.screen_factor > 1
+        sur = [surrogate_lib.init(G, cfg.n_objectives,
+                                  hidden=cfg.surrogate_hidden,
+                                  seed=cfg.seed)] if screened else [None]
+        state = None
+        if ckpt is not None and resume:
+            step = ckpt.latest_step()
+            if step is not None:
+                restored = restore_search_state(
+                    ckpt, step, cfg.pop_size, G, n_obj=cfg.n_objectives,
+                    surrogate_like=sur[0] if screened else None)
+                if screened:
+                    state, sur[0] = restored
+                else:
+                    state = restored
+        on_gen = None
+        if ckpt is not None:
+            # blocking: the state is a few KB and the atomic-commit rename
+            # must land before the next generation can be declared done.
+            on_gen = lambda st: ckpt.save(
+                st.generation, search_state_tree(st, sur[0]), blocking=True)
+        screen_fn = on_eval = None
+        if screened:
+            def on_eval(genomes, fitness):
+                sur[0] = surrogate_lib.observe(sur[0], genomes, fitness,
+                                               steps=cfg.surrogate_steps)
 
-        screen_fn = lambda cands: surrogate_lib.screen(sur[0], cands,
-                                                       cfg.pop_size)
-    pop, fit = nsga2.evolve(
-        make_eval_fn(data, sizes, cfg, mesh=mesh), G, pop_size=cfg.pop_size,
-        generations=cfg.generations, seed=cfg.seed, init=init, log=log,
-        state=state, on_generation=on_gen,
-        offspring_factor=cfg.screen_factor, screen_fn=screen_fn,
-        on_evaluated=on_eval)
-    pg, pf = nsga2.pareto_front(pop, fit)
-    if cfg.frontend is not None:
-        decode = lambda g: decode_genome_cosearch(
-            jnp.asarray(g), C, cfg.bits, cfg.min_levels, cfg.frontend)
-    elif cfg.faulttol is not None:
-        decode = lambda g: decode_genome_faulttol(
-            jnp.asarray(g), C, cfg.bits, cfg.min_levels, cfg.faulttol)
-    else:
-        decode = lambda g: decode_genome(jnp.asarray(g), C, cfg.bits,
-                                         cfg.min_levels)
-    if return_trained:
-        return pg, pf, decode, train_pareto_front(pg, data, sizes, cfg)
-    return pg, pf, decode
+            screen_fn = lambda cands: surrogate_lib.screen(sur[0], cands,
+                                                           cfg.pop_size)
+        pop, fit = nsga2.evolve(
+            make_eval_fn(data, sizes, cfg, mesh=mesh), G,
+            pop_size=cfg.pop_size, generations=cfg.generations,
+            seed=cfg.seed, init=init, log=log, state=state,
+            on_generation=on_gen,
+            offspring_factor=cfg.screen_factor, screen_fn=screen_fn,
+            on_evaluated=on_eval)
+        pg, pf = nsga2.pareto_front(pop, fit)
+        if cfg.frontend is not None:
+            decode = lambda g: decode_genome_cosearch(
+                jnp.asarray(g), C, cfg.bits, cfg.min_levels, cfg.frontend)
+        elif cfg.faulttol is not None:
+            decode = lambda g: decode_genome_faulttol(
+                jnp.asarray(g), C, cfg.bits, cfg.min_levels, cfg.faulttol)
+        else:
+            decode = lambda g: decode_genome(jnp.asarray(g), C, cfg.bits,
+                                             cfg.min_levels)
+        if return_trained:
+            return pg, pf, decode, train_pareto_front(pg, data, sizes, cfg)
+        return pg, pf, decode
 
 
 def run_gradient_search(data: Dict, sizes, cfg: SearchConfig,
