@@ -491,7 +491,7 @@ def _mc_instance_accuracies(designs: Sequence[DeployedClassifier],
     d0 = designs[0]
     spec = d0.spec
     with obs.span("deploy.mc_operands"):
-        masks = jnp.stack([jnp.asarray(d.mask, jnp.int32) for d in designs])
+        masks = np.stack([np.asarray(d.mask, np.int32) for d in designs])
         xj = jnp.asarray(np.asarray(x, np.float32))
         yj = jnp.asarray(np.asarray(y))
         # a §15 fault-tolerant front (TMR/calibrate provenance, or an
@@ -502,6 +502,7 @@ def _mc_instance_accuracies(designs: Sequence[DeployedClassifier],
         ft = (isinstance(draws, ft_redundancy.RedundantDraws)
               or any(d.tmr is not None or d.calibrated for d in designs))
         if ft:
+            masks = jnp.asarray(masks)
             if draws is None:
                 draws = ft_redundancy.draw_redundant(
                     spec.bits, masks.shape[1], samples if samples else 32,
@@ -515,13 +516,10 @@ def _mc_instance_accuracies(designs: Sequence[DeployedClassifier],
             operands = faulttol_cal.mc_operands_ft(spec, nonideal, masks,
                                                    tmr, cal, draws)
         else:
-            if draws is None:
-                draws = nonideal_lib.draw(spec.bits, masks.shape[1],
-                                          samples if samples else 32,
-                                          nonideal)
             entry = "mc_eval_population"
-            operands = nonideal_lib.mc_operands(spec, nonideal, masks,
-                                                draws=draws)
+            operands = nonideal_lib.compiled_mc_operands(
+                spec, nonideal, masks, draws=draws,
+                samples=samples if samples else 32)
     with obs.span("deploy.mc_launch"):
         xq_mc = dispatch.dispatch(entry, xj, *operands, spec=spec,
                                   interpret=interpret)   # (D, S, M, C)
@@ -671,15 +669,17 @@ def make_nonideal_bank_fn(designs: Sequence[DeployedClassifier],
     designs = list(designs)
     d0 = designs[0]
     spec = d0.spec
-    masks = jnp.stack([jnp.asarray(d.mask, jnp.int32) for d in designs])
+    masks = np.stack([np.asarray(d.mask, np.int32) for d in designs])
     if samples is None:
         samples = instance + 1
     if not 0 <= instance < samples:
         raise ValueError(f"instance {instance} outside the "
                          f"{samples}-sample MC stream")
-    draws = nonideal_lib.draw(spec.bits, masks.shape[1], samples, nonideal)
-    one = nonideal_lib.Draws(*(a[instance:instance + 1] for a in draws))
-    mc = nonideal_lib.mc_operands(spec, nonideal, masks, draws=one)
+    # the report's own compiled operands, cut to the one instance
+    lb, ub, values, lo, scale = nonideal_lib.compiled_mc_operands(
+        spec, nonideal, masks, samples=samples)
+    one = slice(instance, instance + 1)
+    mc = (lb[:, one], ub[:, one], values, lo[one], scale[one])
     params = _stacked_model_params(designs)
     apply = svm_lib.apply_svm if d0.kind == "svm" else mlp_lib.apply_mlp
 

@@ -41,11 +41,14 @@ accuracy equals the exported accuracy exactly (tests/test_nonideal.py).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro import obs
 
 ROBUST_OBJECTIVES = ("expected", "worst", "yield")
 
@@ -154,10 +157,24 @@ def draw(bits: int, channels: int, samples: int,
          nonideal: NonIdealSpec) -> Draws:
     """Draw the full randomness block for ``samples`` MC instances —
     a pure function of ``nonideal.seed`` and the shapes."""
+    return draw_from_key(jax.random.PRNGKey(nonideal.seed), bits, channels,
+                         samples)
+
+
+def prng_key(seed: int) -> np.ndarray:
+    """``jax.random.PRNGKey(seed)``'s raw threefry words, built on the
+    host: (hi, lo) uint32. JAX reads an int seed as int64 in 64-bit mode
+    and as its low 32 bits otherwise, where hi is 0."""
+    s = int(seed)
+    hi = (s >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([hi, s & 0xFFFFFFFF], np.uint32)
+
+
+def draw_from_key(key, bits: int, channels: int, samples: int) -> Draws:
+    """``draw`` from a raw PRNG key (traceable: the key may be data)."""
     if samples < 1:
         raise ValueError(f"need >= 1 MC sample, got {samples}")
     nodes = 2 ** bits - 1
-    key = jax.random.PRNGKey(nonideal.seed)
     k1, k2, k3, k4 = jax.random.split(key, 4)
     shape = (samples, channels, nodes)
     return Draws(
@@ -279,6 +296,41 @@ def mc_operands(spec, nonideal: NonIdealSpec, mask: jnp.ndarray,
     lb, ub = instance_bounds(mask, spec.bits, draws, nonideal)
     lo, scale = instance_rows(spec, channels, draws, nonideal)
     return lb, ub, level_value_rows(spec, channels), lo, scale
+
+
+def compiled_mc_operands(spec, nonideal: NonIdealSpec, mask,
+                         draws: Optional[Draws] = None,
+                         samples: Optional[int] = None):
+    """``mc_operands`` as one compiled program per (spec, shapes, sigmas):
+    the draw and the interval compilation in one dispatch rather than
+    hundreds of eager ops. The mask, and the key (``prng_key(seed)``) or
+    the caller's ``draws``, are data, so a fresh wafer seed reuses the
+    compiled program. The sigmas and fault rate are compile-time
+    constants, as the co-search's static ``cfg.nonideal`` makes them in
+    its own graph: XLA then folds ``sigma_range * span`` the same way in
+    both, and the operands match the search's bit for bit (DESIGN.md
+    §10). Counter ``deploy.operand_traces`` counts its traces."""
+    if draws is None:
+        if samples is None:
+            raise ValueError("pass draws= or samples=")
+        key, samples = prng_key(nonideal.seed), int(samples)
+    else:
+        key, samples = None, None
+    return _compiled_mc_operands(
+        mask, key, draws, spec=spec, samples=samples,
+        sigma_offset=nonideal.sigma_offset,
+        sigma_range=nonideal.sigma_range, fault_rate=nonideal.fault_rate)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "spec", "samples", "sigma_offset", "sigma_range", "fault_rate"))
+def _compiled_mc_operands(mask, key, draws, *, spec, samples, sigma_offset,
+                          sigma_range, fault_rate):
+    obs.count("deploy.operand_traces")             # runs once per trace
+    if draws is None:
+        draws = draw_from_key(key, spec.bits, mask.shape[-2], samples)
+    nonideal = NonIdealSpec(sigma_offset, sigma_range, fault_rate)
+    return mc_operands(spec, nonideal, mask, draws=draws)
 
 
 def mc_quantize(x, mask, spec, nonideal: NonIdealSpec, *,

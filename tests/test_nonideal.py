@@ -65,6 +65,55 @@ def test_draws_are_mask_independent_and_seeded():
     assert d1.samples == 6 and d1.eps.shape == (6, 4, 7)
 
 
+# ------------------------------------------- compiled operand entry
+@pytest.mark.parametrize("seed", [0, 1, 2 ** 31 - 1, 2 ** 31, 2 ** 32 + 5,
+                                  -7])
+def test_host_prng_key_is_jax_prngkey(seed):
+    assert NonIdealSpec(seed=seed).seed == seed          # admitted as is
+    want = jax.random.key_data(jax.random.PRNGKey(seed))
+    np.testing.assert_array_equal(nonideal.prng_key(seed), np.asarray(want))
+
+
+@pytest.mark.parametrize("path", ["seed", "draws"])
+@pytest.mark.parametrize("lead", [(), (4,)], ids=["single", "population"])
+def test_compiled_operands_match_in_graph_mc_operands(path, lead):
+    """The compiled entry equals, bit for bit, ``mc_operands`` traced
+    inside a jit with the sigmas static and the draws as data — the way
+    the co-search's generation builds them — whether it draws from the
+    seed or takes the caller's draws."""
+    rng = np.random.default_rng(5)
+    spec = AdcSpec(bits=3, vmin=(0.0, -1.0, 0.5), vmax=(1.0, 1.0, 2.5))
+    masks = _rand_masks(rng, 4, 3, 3)
+    masks = np.asarray(masks[0] if not lead else masks)
+    ni = NonIdealSpec(sigma_offset=0.5, sigma_range=0.03, fault_rate=0.1,
+                      seed=2 ** 31 + 3)
+    draws = nonideal.draw(3, 3, 6, ni)
+    in_graph = jax.jit(lambda m, d: nonideal.mc_operands(spec, ni, m,
+                                                         draws=d))
+    want = in_graph(jnp.asarray(masks), draws)
+    kw = dict(samples=6) if path == "seed" else dict(draws=draws)
+    got = nonideal.compiled_mc_operands(spec, ni, masks, **kw)
+    assert len(got) == len(want) == 5
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_compiled_operands_trace_once_across_seeds():
+    """Three wafers at one shape: one trace, since the seed is data."""
+    from repro import obs
+    rng = np.random.default_rng(6)
+    spec = AdcSpec(bits=2)
+    masks = np.asarray(_rand_masks(rng, 3, 5, 2))       # a shape of its own
+    ni = NonIdealSpec(sigma_offset=0.4, sigma_range=0.02, fault_rate=0.05)
+    before = obs.counters().get("deploy.operand_traces", 0)
+    outs = [nonideal.compiled_mc_operands(spec, ni.replace(seed=s), masks,
+                                          samples=9)
+            for s in (11, 2 ** 31 + 11, 12)]
+    assert obs.counters()["deploy.operand_traces"] - before == 1
+    assert not np.array_equal(np.asarray(outs[0][0]), np.asarray(outs[2][0]))
+
+
 # ------------------------------------------------- kernel-vs-oracle parity
 @pytest.mark.parametrize("spec", [
     AdcSpec(bits=3),

@@ -110,4 +110,7 @@ def test_robustness_call_has_its_four_children(searched):
                    for r in kids[call.id])
     for name in CALL_CHILDREN:
         assert sum(r.name == name for r in recs) == 2
-    assert obs.counters() == {"deploy.instances": 2 * len(designs) * 3}
+    c = obs.counters()
+    # the operands compile once per shape: on the first call, if at all
+    assert c.pop("deploy.operand_traces", 0) <= 1
+    assert c == {"deploy.instances": 2 * len(designs) * 3}

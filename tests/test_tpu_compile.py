@@ -103,3 +103,31 @@ def test_entry_compiles_for_v5e(name, bits, one_chip):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), (
         f"{name}: no Mosaic kernel in the compiled program")
+
+
+@pytest.mark.parametrize("path", ["seed", "draws"])
+def test_mc_operands_compile_for_v5e(path, one_chip):
+    """The robustness call's operand program (``compiled_mc_operands``)
+    at the robust benchmark's shapes (D = 8, S = 1,024, bits 4), with the
+    wafer's key or its draws as data."""
+    from repro.core import nonideal
+    s, n = 1024, 16
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    masks = sds((D, C, n), jnp.int32)
+    kw = dict(spec=AdcSpec(bits=4), sigma_offset=0.5, sigma_range=0.01,
+              fault_rate=0.01)
+    if path == "seed":
+        lowered = nonideal._compiled_mc_operands.lower(
+            masks, sds((2,), jnp.uint32), None, samples=s, **kw)
+    else:
+        draws = nonideal.Draws(sds((s, C, n - 1)), sds((s, C, n - 1)),
+                               sds((s, C, n - 1), jnp.bool_),
+                               sds((s, C, 2)))
+        lowered = nonideal._compiled_mc_operands.lower(
+            masks, None, draws, samples=None, **kw)
+    shapes = [o.shape for o in lowered.out_info]
+    assert shapes == [(D, s, C, n), (D, s, C, n), (C, n), (s, C), (s, C)]
+    lowered.compile()
