@@ -20,6 +20,8 @@ from bench.lib import harness  # noqa: E402
 SMALL = {
     "search.cardio": {"pop": 4, "generations": 2, "sample": 8},
     "robust.cardio": {"samples": 32},
+    "search.whitewine": {"pop": 4, "generations": 2, "sample": 8},
+    "robust.whitewine": {"samples": 32},
 }
 
 

@@ -19,6 +19,9 @@ SMALL = {
     "search.cardio": {"pop": 4, "generations": 2, "train_steps": 12,
                       "sample": 32},
     "robust.cardio": {"samples": 8},
+    "search.whitewine": {"pop": 4, "generations": 2, "train_steps": 12,
+                         "sample": 32},
+    "robust.whitewine": {"samples": 8},
 }
 
 
